@@ -40,6 +40,10 @@ def main() -> None:
         ap.error("--check-serving-against needs the serve suite in the run "
                  "(drop --only or include serve in it)")
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (
         kernel_bench,
         overflow_profile,

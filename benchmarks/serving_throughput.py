@@ -247,8 +247,7 @@ def bench_int_decode(arch: str = "qwen2-1.5b", steps: int = 20,
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     qparams = quantize_tree(params, bits=8, min_size=1 << 10, min_dim=16)
-    il = IntegerLinConfig(policy="sorted_tiled_seq", acc_bits=24, k_tile=64,
-                          backend="jnp")
+    il = IntegerLinConfig(policy="sorted_tiled_seq", acc_bits=24, k_tile=64)
     rng = np.random.default_rng(0)
     cal_batches = [
         {"tokens": jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 16)))}

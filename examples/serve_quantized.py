@@ -118,7 +118,7 @@ from repro.core.dispatch import IntegerLinConfig  # noqa: E402
 int_eng = ServingEngine(
     model, qparams, num_slots=3, max_len=64,
     int_lin=IntegerLinConfig(policy="sorted_tiled_seq", acc_bits=24,
-                             k_tile=64, backend="jnp"),
+                             k_tile=64),
 )
 frozen = int_eng.calibrate(
     [{k: jnp.asarray(v) for k, v in data.next_batch().items()}

@@ -1,8 +1,7 @@
-"""PQS core: prune, quantize, and sort for low-bitwidth accumulation."""
+"""PQS core: prune, quantize, and sort for low-bitwidth accumulation.
 
-from repro.core.dispatch import (  # noqa: F401
-    IntegerLinConfig,
-    integer_lin,
-    pqs_dot,
-)
-from repro.core.pqs import PQSConfig  # noqa: F401
+Import the submodules directly (``repro.core.dispatch.pqs_dot``, ...):
+``core.dispatch`` imports the kernel package, whose modules import
+``core`` submodules, so re-exporting it here would make
+``import repro.kernels.ops`` on its own circular.
+"""

@@ -62,8 +62,8 @@ step instead of all-gathering all S partials — and
 tail (``PendingCombine``) so independent compute overlaps it. The
 census counts every shard's local dot and reports combine-step
 overflows separately (``Census.n_combine``). This is what carries a
-single dot past the compiled sort kernels' per-device
-``ops.MAX_STREAM_K`` bound: per-device K footprint is K/S.
+single dot past the sort kernels' per-device ``ops.MAX_STREAM_K``
+bound: per-device K footprint is K/S.
 """
 
 from __future__ import annotations
@@ -479,13 +479,18 @@ def _sharded_dot(
     examined dot), and the per-member combine-count shares are psummed
     over ``k_axis`` as well to reconstruct the exact per-tree total.
 
+    Either way the (M, N) blocks are then all-gathered, so every member
+    returns the whole result: the float ops a model runs on it never
+    split a sum across devices (a split sum rounds differently), and a
+    meshed model computes bit-identically to one device.
+
     ``defer=True`` splits the dot into two shard_maps: phase 1 returns
     the global (S, M, N) register array laid out on ``k_axis`` wrapped
     in a ``PendingCombine``; its finish runs the exchange. Tracing both
     phases into one jitted step lets XLA overlap the exchange with any
     compute independent of the combined value.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import data_axes
@@ -515,7 +520,14 @@ def _sharded_dot(
             f"K axis {k_axis!r} was degraded from the operand specs "
             f"({x_spec}, {w_spec}) despite pre-padding"
         )
-    out_spec = P(x_spec[0], w_row)
+    out_spec = P()  # whole result on every member (``gather`` below)
+
+    def gather(out):
+        if x_spec[0] is not None:
+            out = jax.lax.all_gather(out, x_spec[0], axis=0, tiled=True)
+        if w_row is not None:
+            out = jax.lax.all_gather(out, w_row, axis=1, tiled=True)
+        return out
     # census counters must be summed only over axes that actually
     # partition the dots; replicated axes would multiply-count
     used: list[str] = []
@@ -548,18 +560,19 @@ def _sharded_dot(
                     nc = jnp.sum(novf).astype(jnp.int32)
                     nc = jax.lax.psum(nc, tuple(used) + (k_axis,))
                     cns = cns._replace(n_combine=cns.n_combine + nc)
+            out = gather(out)
             return (out, cns) if with_census else out
 
         out_specs = (out_spec, cns_specs) if with_census else out_spec
         return shard_map(
-            body, mesh, in_specs=(x_spec, w_spec), out_specs=out_specs,
-            check_rep=False,
+            body, mesh=mesh, in_specs=(x_spec, w_spec), out_specs=out_specs,
+            check_vma=False,
         )(x2, w)
 
     # deferred: phase 1 materializes each member's register as its slot
     # of a global (S, M, N) array laid out along k_axis; phase 2 — the
     # exchange — dispatches when the caller consumes the PendingCombine
-    part_spec = P(k_axis, *out_spec)
+    part_spec = P(k_axis, x_spec[0], w_row)
 
     def body1(xl, wl):
         out, cns = _local_dot(xl, wl, with_census=with_census, **kw)
@@ -573,8 +586,8 @@ def _sharded_dot(
 
     out_specs1 = (part_spec, cns_specs) if with_census else part_spec
     res1 = shard_map(
-        body1, mesh, in_specs=(x_spec, w_spec), out_specs=out_specs1,
-        check_rep=False,
+        body1, mesh=mesh, in_specs=(x_spec, w_spec), out_specs=out_specs1,
+        check_vma=False,
     )(x2, w)
     parts, cns1 = res1 if with_census else (res1, None)
 
@@ -584,11 +597,11 @@ def _sharded_dot(
         )
         nc = jnp.sum(novf).astype(jnp.int32)
         nc = jax.lax.psum(nc, tuple(used) + (k_axis,))
-        return out, nc
+        return gather(out), nc
 
     combine_fn = shard_map(
-        body2, mesh, in_specs=(part_spec,), out_specs=(out_spec, P()),
-        check_rep=False,
+        body2, mesh=mesh, in_specs=(part_spec,), out_specs=(out_spec, P()),
+        check_vma=False,
     )
 
     def finish(p):
@@ -662,7 +675,8 @@ def pqs_dot(
     ``shard_map``: M sharded over ``m_axes`` (default: the mesh's data
     axes), N over ``n_axis`` ("model"), K accumulated whole inside each
     shard — bit-identical to the single-device result (compressed
-    weights shard their N rows the same way).
+    weights shard their N rows the same way), and returned whole on
+    every member.
 
     ``k_shards=S`` (without a mesh) partitions K into S contiguous,
     equal, policy-padded slices accumulated independently under the

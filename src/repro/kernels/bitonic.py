@@ -4,13 +4,16 @@ A sorting *network* (paper §6: "sorting networks such as the bitonic
 algorithm are popular for sorting arrays in hardware") has no
 data-dependent control flow, which makes it the natural TPU mapping for the
 paper's sort stage: log2(n)*(log2(n)+1)/2 stages of elementwise
-min/max over lane-aligned slices.
+min/max.
 
-Every partner exchange at stride j is expressed as a reshape to
-(..., n/(2j), 2, j) and a flip of the middle axis — no gathers, so the
-same code runs inside a Pallas kernel body and in plain jnp (the ref
-oracle). Direction masks are rebuilt from broadcasted_iota inside the
-trace, since Pallas kernel bodies may not capture host constants.
+The network runs on the LEADING axis. Inside a Pallas kernel the sorted
+axis is K and the trailing (bm, bn) dims are the output block, so every
+element of a K slice is one (8, 128) tile: a partner exchange at stride j
+is a reshape of leading dims to (..., 2, j, bm, bn) and a static pick of
+the two halves, and the direction of each k-block comes from splitting
+the leading axis once more — no gathers, no ``rev``, no lane-axis
+indexing, nothing Mosaic refuses. The same code is the jnp oracle's
+network (``bitonic_sort`` sorts any axis by moving it to the front).
 """
 
 from __future__ import annotations
@@ -32,56 +35,64 @@ def _stages(n: int) -> list[tuple[int, int]]:
     return out
 
 
-def _take_min_mask(n: int, k: int, j: int, ascending: bool) -> jnp.ndarray:
-    """(1, n) traced mask: keep min at this lane? Built from iota inside the
-    trace (Pallas kernels may not capture host constants)."""
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
-    partner = jnp.bitwise_xor(idx, j)
-    up = (jnp.bitwise_and(idx, k) == 0)  # this k-block sorts ascending
-    take_min = jnp.where(idx < partner, up, jnp.logical_not(up))
-    if not ascending:
-        take_min = jnp.logical_not(take_min)
-    return take_min
+def _exchange(x: jax.Array, k: int, j: int, ascending: bool) -> jax.Array:
+    """One network stage on axis 0: partners i and i^j; k-blocks whose
+    index has bit k clear sort ``ascending``, the others the other way."""
+    n, rest = x.shape[0], x.shape[1:]
+
+    def pair(y, up):  # y (b, 2, j, *rest): halves are the partners
+        a, b = y[:, 0], y[:, 1]
+        lo, hi = jnp.minimum(a, b), jnp.maximum(a, b)
+        return jnp.stack([lo, hi] if up else [hi, lo], axis=1)
+
+    if k >= n:  # a single k-block: one direction
+        return pair(x.reshape(n // (2 * j), 2, j, *rest),
+                    ascending).reshape(x.shape)
+    xr = x.reshape(n // (2 * k), 2, k // (2 * j), 2, j, *rest)
+    halves = [
+        pair(xr[:, d].reshape(-1, 2, j, *rest), ascending == (d == 0))
+        .reshape(n // (2 * k), k // (2 * j), 2, j, *rest)
+        for d in (0, 1)
+    ]
+    return jnp.stack(halves, axis=1).reshape(x.shape)
 
 
-def bitonic_sort(x: jnp.ndarray, ascending: bool = True) -> jnp.ndarray:
-    """Sort the last axis (length must be a power of two)."""
-    n = x.shape[-1]
+def bitonic_sort(x: jax.Array, ascending: bool = True,
+                 axis: int = -1) -> jax.Array:
+    """Sort ``axis`` (length must be a power of two)."""
+    axis = axis % x.ndim
+    n = x.shape[axis]
     if n & (n - 1):
         raise ValueError(f"bitonic length must be a power of 2, got {n}")
-    lead = x.shape[:-1]
-    mask_shape = (1,) * max(len(lead), 1) + (n,)
+    if axis:
+        x = jnp.moveaxis(x, axis, 0)
     for k, j in _stages(n):
-        xr = x.reshape(*lead, n // (2 * j), 2, j)
-        swapped = jnp.flip(xr, axis=-2).reshape(*lead, n)
-        mn = jnp.minimum(x, swapped)
-        mx = jnp.maximum(x, swapped)
-        take_min = _take_min_mask(n, k, j, ascending).reshape(mask_shape)
-        x = jnp.where(take_min, mn, mx)
-    return x
+        x = _exchange(x, k, j, ascending)
+    return jnp.moveaxis(x, 0, axis) if axis else x
 
 
 _NEG_INF = jnp.iinfo(jnp.int32).min
 _POS_INF = jnp.iinfo(jnp.int32).max
 
 
-def pairwise_round_bitonic(prods: jnp.ndarray) -> jnp.ndarray:
+def pairwise_round_bitonic(prods: jax.Array, axis: int = -1) -> jax.Array:
     """One split/sort/pairwise-add round (paper Alg. 1 body) built on the
     sorting network — semantically identical to
     ``core.sorted_accum.pairwise_round`` (tested bit-exact) but expressed
     entirely in reshape/min/max/where, so it runs inside Pallas kernels.
     """
     pos = jnp.where(prods > 0, prods, _NEG_INF)
-    pos = bitonic_sort(pos, ascending=False)  # positives first, descending
+    pos = bitonic_sort(pos, ascending=False, axis=axis)  # positives first
     pos = jnp.where(pos == _NEG_INF, 0, pos)
     neg = jnp.where(prods < 0, prods, _POS_INF)
-    neg = bitonic_sort(neg, ascending=True)  # most-negative first
+    neg = bitonic_sort(neg, ascending=True, axis=axis)  # most-negative first
     neg = jnp.where(neg == _POS_INF, 0, neg)
     return pos + neg
 
 
-def sorted_order_bitonic(prods: jnp.ndarray, rounds: int = 1) -> jnp.ndarray:
+def sorted_order_bitonic(prods: jax.Array, rounds: int = 1,
+                         axis: int = -1) -> jax.Array:
     out = prods
     for _ in range(rounds):
-        out = pairwise_round_bitonic(out)
+        out = pairwise_round_bitonic(out, axis)
     return out

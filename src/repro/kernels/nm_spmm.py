@@ -12,10 +12,13 @@ Two implementations of every policy x sparse-storage composition
 (selected by ``kernels.ops.nm_policy_matmul`` via ``nm_impl`` /
 ``REPRO_PQS_NM_IMPL``):
 
-expand (``nm_seq_policy_matmul`` / ``nm_sort_matmul``) — expand each
-  (bn, bg, n_keep) slab to a dense (bn, bg*m) block in VMEM via an
-  iota-compare one-hot einsum (MXU-friendly, no gathers) and feed the
-  exact dense ``sorted_matmul`` kernel bodies. Saves bytes, not FLOPs:
+expand (``nm_seq_policy_matmul`` / ``nm_sort_matmul``) — expand the
+  compressed slab to a dense block in VMEM (no gathers) and feed the
+  exact dense ``sorted_matmul`` kernel bodies. The K-streaming kernel
+  streams K-major (n_keep, bg, bn) slabs and expands them with selects
+  (``expand_nm_block``), the form that compiles for TPU; the
+  interpret-only sort kernels use the one-hot ``expand_nm_slab``.
+  Saves bytes, not FLOPs:
   the contraction still runs over the full dense K. The expanded slab is
   bit-identical to the dense weight block (pruned positions expand to
   zero, and zero partial products are sign-neutral and additively inert
@@ -38,10 +41,11 @@ gather (``nm_gather_seq_policy_matmul`` / ``nm_gather_sort_matmul``) —
   length, so gathered tiles pad L = bg*n_keep up to next_pow2(L) <=
   bg*m — still at most the dense tile, usually far below it.
 
-Expansion cost is n_keep*m multiply-adds per weight; the gather is one
+Expansion cost is n_keep*m selects per weight; the gather is one
 dynamic-index load per kept product (same per-element ``take_along_axis``
-idiom as ``sorted_stream._gather_tile`` — the standing Mosaic-on-real-TPU
-caveat applies, interpret mode is exact).
+idiom as ``sorted_stream._gather_tile``). The gather kernels do not lower
+to Mosaic: they run in interpret mode only, and ``kernels.ops`` refuses
+them on a TPU.
 """
 
 from __future__ import annotations
@@ -77,80 +81,54 @@ def expand_nm_slab(vals: jax.Array, idx: jax.Array, m_group: int
     return nm_onehot_expand(vals.astype(jnp.int32), idx, m_group)
 
 
-def _kernel(x_ref, v_ref, i_ref, o_ref, *, m_group: int):
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    wb = expand_nm_slab(v_ref[...], i_ref[...], m_group)  # (bn, bg*m)
-    xb = x_ref[...].astype(jnp.int32)  # (bm, bg*m)
-    o_ref[...] += jax.lax.dot_general(
-        xb, wb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32
-    )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("m_group", "bm", "bn", "bg", "interpret"),
-)
-def nm_spmm(
-    x: jax.Array,  # (M, K) int8, K = G * m_group
-    values: jax.Array,  # (N, G, n_keep) int8
-    indices: jax.Array,  # (N, G, n_keep) int32
-    *,
-    m_group: int = 16,
-    bm: int = 128,
-    bn: int = 128,
-    bg: int = 32,
-    interpret: bool = False,
-) -> jax.Array:
-    m, k = x.shape
-    n, g, n_keep = values.shape
-    assert k == g * m_group, (k, g, m_group)
-    assert m % bm == 0 and n % bn == 0 and g % bg == 0, (m, n, g, bm, bn, bg)
-    grid = (m // bm, n // bn, g // bg)
-    kern = functools.partial(_kernel, m_group=m_group)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (bm, bg * m_group), lambda i, j, kk: (i, kk)
-            ),
-            pl.BlockSpec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
-            pl.BlockSpec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        interpret=interpret,
-    )(x, values, indices)
-
-
 # ---------------------------------------------------------------------------
 # policy x sparse-storage composition kernels
 # ---------------------------------------------------------------------------
 
 
+def expand_nm_block(vals: jax.Array, idx: jax.Array, m_group: int
+                    ) -> jax.Array:
+    """K-major compressed block (n_keep, bg, bn) -> dense (bg*m, bn) int8.
+
+    Rows come out p-major: row ``p*bg + g`` holds K offset ``g*m + p`` of
+    the block (``nm_seq_policy_matmul`` permutes the activation columns
+    the same way). Each dense position receives at most one kept value
+    (padded slots carry value 0), so the select-sum is exact — the same
+    expansion as ``core.pruning.nm_onehot_expand``, written as (bg, bn)
+    selects that the TPU compiler lowers, with no lane-merging reshape.
+    """
+    v, i = vals.astype(jnp.int32), idx.astype(jnp.int32)
+    rows = [
+        functools.reduce(jnp.add, [jnp.where(i[j] == p, v[j], 0)
+                                   for j in range(v.shape[0])])
+        for p in range(m_group)
+    ]
+    return jnp.concatenate(rows, axis=0).astype(jnp.int8)
+
+
 def _nm_seq_kernel(x_ref, v_ref, i_ref, o_ref, *, policy: str,
-                   acc_bits: int, rounds: int, m_group: int):
-    """``sorted_matmul._seq_body`` fed by the one-hot expand slab."""
+                   acc_bits: int, rounds: int, k_tile: int, m_group: int,
+                   interpret: bool):
+    """``sorted_matmul._seq_body`` fed by the in-VMEM expand block."""
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    wb = expand_nm_slab(v_ref[...], i_ref[...], m_group)  # (bn, bg*m)
-    _seq_body(x_ref[...].astype(jnp.int32), wb, o_ref, policy=policy,
-              acc_bits=acc_bits, rounds=rounds)
+    bg = v_ref.shape[1]
+    cols = [p * bg + g for g in range(bg) for p in range(m_group)]
+    _seq_body(x_ref[...], expand_nm_block(v_ref[...], i_ref[...], m_group),
+              o_ref, policy=policy, acc_bits=acc_bits, rounds=rounds,
+              k_tile=k_tile, interpret=interpret, cols=cols)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("policy", "acc_bits", "rounds", "m_group", "bm", "bn",
-                     "bg", "interpret"),
+                     "bg", "k_tile", "interpret"),
 )
 def nm_seq_policy_matmul(
-    x: jax.Array,  # (M, K) int carrier, K = G * m_group
+    x: jax.Array,  # (M, K) int8, K = G * m_group
     values: jax.Array,  # (N, G, n_keep) int8
     indices: jax.Array,  # (N, G, n_keep) int32
     *,
@@ -161,36 +139,47 @@ def nm_seq_policy_matmul(
     bm: int = 8,
     bn: int = 128,
     bg: int = 16,
+    k_tile: int = 256,
     interpret: bool = False,
 ) -> jax.Array:
     """K-streaming policies on compressed storage: wide|clip|wrap|
-    sorted_tiled_seq. For sorted_tiled_seq, ``bg * m_group`` IS the
-    paper's k_tile (and must be a power of two for the bitonic network),
-    so tile boundaries coincide with the dense kernel's."""
+    sorted_tiled_seq. A grid step covers bk = bg * m_group dense K
+    columns; for sorted_tiled_seq k_tile (a power of two) must divide bk,
+    so tile boundaries coincide with the dense kernel's.
+
+    The slabs stream K-major as (n_keep, G, N) so a block's N rides the
+    lanes, and the activations' columns are permuted p-major within each
+    block to match ``expand_nm_block``'s rows: the wide dot is one MXU
+    matmul, and the order-sensitive policies still visit K naturally."""
     m, k = x.shape
     n, g, n_keep = values.shape
     assert k == g * m_group, (x.shape, values.shape, m_group)
+    assert x.dtype == jnp.int8, x.dtype
     assert policy in SEQ_POLICIES, policy
+    bk = bg * m_group
     if policy == "sorted_tiled_seq":
-        bk = bg * m_group
-        assert bk & (bk - 1) == 0, f"bg*m_group must be a power of 2: {bk}"
+        assert k_tile & (k_tile - 1) == 0 and bk % k_tile == 0, (bk, k_tile)
     assert m % bm == 0 and n % bn == 0 and g % bg == 0, (m, n, g, bm, bn, bg)
     grid = (m // bm, n // bn, g // bg)
+    xp = x.reshape(m, g // bg, bg, m_group).swapaxes(-1, -2).reshape(m, k)
+    vt = values.transpose(2, 1, 0).astype(jnp.int32)
+    it = indices.transpose(2, 1, 0).astype(jnp.int32)
     kern = functools.partial(_nm_seq_kernel, policy=policy,
                              acc_bits=acc_bits, rounds=rounds,
-                             m_group=m_group)
+                             k_tile=k_tile, m_group=m_group,
+                             interpret=interpret)
     return pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bg * m_group), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
-            pl.BlockSpec((bn, bg, n_keep), lambda i, j, kk: (j, kk, 0)),
+            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((n_keep, bg, bn), lambda i, j, kk: (0, kk, j)),
+            pl.BlockSpec((n_keep, bg, bn), lambda i, j, kk: (0, kk, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
-    )(x, values, indices)
+    )(xp, vt, it)
 
 
 def _nm_sort_kernel(x_ref, v_ref, i_ref, o_ref, *, policy: str,
@@ -338,8 +327,8 @@ def _nm_gather_seq_kernel(x_ref, v_ref, i_ref, o_ref, *, policy: str,
         return
     if policy == "sorted_tiled_seq":
         prods = sorted_order_bitonic(pad_last_pow2(prods), rounds)
-    o_ref[...] = _stepwise(prods, o_ref[...], acc_bits,
-                           saturate=(policy != "wrap"))
+    o_ref[...] = _stepwise(jnp.moveaxis(prods, -1, 0), o_ref[...],
+                           acc_bits, saturate=(policy != "wrap"))
 
 
 @functools.partial(
@@ -421,8 +410,8 @@ def _nm_gather_sort_kernel(x_ref, v_ref, i_ref, o_ref, *, policy: str,
             tiles.reshape(bm_, bn_, -1), lp, rounds,
             order_fn=sorted_order_bitonic,
         )
-    o_ref[...] = _stepwise(ordered, jnp.zeros_like(o_ref), acc_bits,
-                           saturate=True)
+    o_ref[...] = _stepwise(jnp.moveaxis(ordered, -1, 0),
+                           jnp.zeros_like(o_ref), acc_bits, saturate=True)
 
 
 @functools.partial(

@@ -16,6 +16,7 @@ jnp backend, so both backends realize the same order.
 
 from __future__ import annotations
 
+import math
 import os
 
 import jax
@@ -58,6 +59,10 @@ MAX_RESIDENT_K = 4096
 MAX_STREAM_K = 65536
 
 SORT_IMPLS = ("auto", "onepass", "twopass")
+
+# TPU lane width: a compiled block's K extent is a multiple of it (or the
+# whole K), so a sort tile below it shares its block with its neighbours
+LANES = 128
 
 # Per-platform (bm, bn) defaults for policy_matmul, keyed by
 # jax.default_backend(). The sort policies keep bm small: their product
@@ -194,16 +199,16 @@ def padded_k(k: int, policy: str, k_tile: int) -> int:
 
 
 def _as_int8(a: jax.Array) -> jax.Array:
-    """Narrow an integer carrier to int8 for the streaming sort slabs.
+    """Narrow an integer carrier to the int8 the kernels stream.
 
-    Slab VMEM is what scales with K in the two-pass pipeline, and
-    carriers hold int8 values by the ``pqs_dot`` contract, so the cast
-    is lossless for every legitimate caller. A silently wrapped
-    out-of-contract value would diverge from the jnp backend, so on
-    concrete (non-traced) operands the contract is checked loudly; the
-    check is one cheap reduction next to a sort matmul. Traced calls
-    (jitted serving steps, whose carriers come from int8 quantizers)
-    trust the contract.
+    The TPU's MXU multiplies int8 (it has no int32 x int32 dot), and the
+    two-pass sort pipeline's VMEM scales with its int8 slabs. Carriers
+    hold int8 values by the ``pqs_dot`` contract, so the cast is lossless
+    for every legitimate caller. A silently wrapped out-of-contract value
+    would diverge from the jnp backend, so on concrete (non-traced)
+    operands the contract is checked loudly; the check is one cheap
+    reduction next to a matmul. Traced calls (jitted serving steps, whose
+    carriers come from int8 quantizers) trust the contract.
     """
     if a.dtype == jnp.int8:
         return a
@@ -211,10 +216,9 @@ def _as_int8(a: jax.Array) -> jax.Array:
         lo, hi = int(jnp.min(a)), int(jnp.max(a))
         if lo < -128 or hi > 127:
             raise ValueError(
-                f"two-pass sort carriers must hold int8 values (pqs_dot "
+                f"kernel carriers must hold int8 values (pqs_dot "
                 f"contract); got range [{lo}, {hi}] in {a.dtype}. Use "
-                "sort_impl='onepass' (K-resident) or backend='jnp' for "
-                "wider products."
+                "backend='jnp' for wider products."
             )
     return a.astype(jnp.int8)
 
@@ -254,12 +258,15 @@ def resolve_sort_impl(kp: int, interpret: bool,
 
 
 def resolve_nm_impl(policy: str, g: int, n_keep: int, m_group: int,
-                    nm_impl: str | None = None) -> str:
+                    nm_impl: str | None = None, compiled: bool = False
+                    ) -> str:
     """Which N:M kernel implementation serves a compressed matmul.
 
     Explicit ``nm_impl`` (or ``REPRO_PQS_NM_IMPL``) wins; ``auto`` picks
     ``gather`` wherever the kept-product contraction can actually save
-    work and falls back to ``expand`` when it cannot:
+    work and falls back to ``expand`` when it cannot. A ``compiled``
+    (TPU) call always resolves ``auto`` to ``expand`` and refuses
+    ``gather``: the per-element gather does not lower to Mosaic.
 
     * ``n_keep >= m_group`` — dense-as-sparse storage: every product is
       kept, gathering reorders full-dense work for no gain;
@@ -279,15 +286,46 @@ def resolve_nm_impl(policy: str, g: int, n_keep: int, m_group: int,
             f"nm_impl (REPRO_PQS_NM_IMPL) must be one of {NM_IMPLS}, "
             f"got {impl!r}"
         )
+    if compiled and impl == "gather":
+        raise ValueError(
+            "nm_impl='gather' does not compile for TPU (its per-element "
+            "activation gather has no Mosaic lowering); use 'expand' or "
+            "'auto', or backend='jnp'"
+        )
     if impl != "auto":
         return impl
-    if n_keep >= m_group:
+    if compiled or n_keep >= m_group:
         return "expand"
     if policy == "wide":
         return "expand"
     if g < GATHER_MIN_G:
         return "expand"
     return "gather"
+
+
+def _refuse_compiled_sort(policy: str) -> None:
+    """The global-permutation kernels exist in interpret mode only: Mosaic
+    refuses their whole-K walks (``dynamic_slice``), in-kernel ``sort``
+    and (bm, bn, 1) tile-sum blocks. A chip never silently falls back to
+    jnp or to the interpreter."""
+    raise ValueError(
+        f"policy={policy!r} has no compiled TPU kernel (the global-sort "
+        "kernels run in interpret mode only); use a K-streaming policy "
+        f"{_sm.SEQ_POLICIES} or backend='jnp'"
+    )
+
+
+def seq_block_k(policy: str, kp: int, k_tile: int,
+                bk: int | None = None) -> int:
+    """K depth of one K-streaming grid step.
+
+    sorted_tiled_seq: the smallest whole number of sort tiles that fills
+    the lane width (a tile never straddles a block); the others: the
+    tuned ``bk`` or a bandwidth-friendly slab of up to 512.
+    """
+    if policy == "sorted_tiled_seq":
+        return max(k_tile, LANES)
+    return bk if bk is not None else min(512, next_pow2(kp))
 
 
 def _blocks_for(policy, m, n, kp, runner, tracing, nm=None):
@@ -368,6 +406,8 @@ def policy_matmul(
         bm = dbm if bm is None else bm
         bn = dbn if bn is None else bn
     if policy in _sm.SORT_POLICIES:
+        if not interpret:
+            _refuse_compiled_sort(policy)
         impl = resolve_sort_impl(kp, interpret, sort_impl)
         xp = _pad_to(_pad_to(x, bm, 0), kp, 1)
         wp = _pad_to(_pad_to(w, kp, 1), bn, 0)
@@ -383,17 +423,13 @@ def policy_matmul(
                 bm=bm, bn=bn, interpret=interpret,
             )
     else:
-        # streaming block depth: the sort tile for sorted_tiled_seq, else
-        # a bandwidth-friendly slab that divides the (padded) K
-        if policy == "sorted_tiled_seq":
-            bk = k_tile
-        elif bk is None:
-            bk = min(512, next_pow2(kp))
+        bk = seq_block_k(policy, kp, k_tile, bk)
         xp = _pad_to(_pad_to(_pad_to(x, bm, 0), kp, 1), bk, 1)
         wp = _pad_to(_pad_to(_pad_to(w, kp, 1), bk, 1), bn, 0)
         out = _sm.seq_policy_matmul(
-            xp, wp, policy=policy, acc_bits=acc_bits, rounds=rounds,
-            bm=bm, bn=bn, bk=bk, interpret=interpret,
+            _as_int8(xp), _as_int8(wp), policy=policy, acc_bits=acc_bits,
+            rounds=rounds, bm=bm, bn=bn, bk=bk, k_tile=k_tile,
+            interpret=interpret,
         )
     return out[:m, :n]
 
@@ -425,7 +461,7 @@ def partial_policy_matmul(
     ``core.sorted_accum.tree_combine`` / ``combine_schedule`` — the same
     schedule whether combined locally or as pairwise mesh exchanges.
     Each shard's K footprint is K/k_shards, which is what carries the
-    compiled sort kernels past ``MAX_STREAM_K`` total K.
+    sort kernels past ``MAX_STREAM_K`` total K.
     """
     if k_shards < 1 or x.shape[1] % k_shards:
         raise ValueError(
@@ -573,7 +609,8 @@ def nm_policy_matmul(
             f"k_tile={k_tile}, m_group={m_group}"
         )
     kp = padded_k(k_dense, policy, k_tile)
-    impl = resolve_nm_impl(policy, g, n_keep, m_group, nm_impl)
+    impl = resolve_nm_impl(policy, g, n_keep, m_group, nm_impl,
+                           compiled=not interpret)
     fam = f"nmg:{policy}" if impl == "gather" else f"nm:{policy}"
     if bm is None and bn is None:
 
@@ -597,6 +634,8 @@ def nm_policy_matmul(
     vp = _pad_to(values, bn, 0)
     ip = _pad_to(indices, bn, 0)
     if policy in _sm.SORT_POLICIES:
+        if not interpret:
+            _refuse_compiled_sort(policy)
         simpl = resolve_sort_impl(kp, interpret, sort_impl)
         if policy == "sorted_tiled":
             # pad G so the compressed groups cover exactly kp columns —
@@ -623,21 +662,33 @@ def nm_policy_matmul(
                 bm=bm, bn=bn, interpret=interpret,
             )
     else:
-        if policy == "sorted_tiled_seq":
-            bg = k_tile // m_group  # the sort block IS the paper's k_tile
-        elif bg is None:
-            bg = max(1, min(512, next_pow2(k_dense)) // m_group)
+        if impl == "gather":
+            if policy == "sorted_tiled_seq":
+                bg = k_tile // m_group  # the sort block IS the k_tile
+            elif bg is None:
+                bg = max(1, min(512, next_pow2(k_dense)) // m_group)
+        else:
+            # the expand block: whole sort tiles (seq_block_k) and a
+            # sublane-aligned number of groups (8 | bg)
+            bk = seq_block_k(policy, k_dense, k_tile,
+                             None if bg is None else bg * m_group)
+            bg = math.lcm(bk, 8 * m_group) // m_group
         g_pad = (-g) % bg
         if g_pad:
             vp = jnp.pad(vp, ((0, 0), (0, g_pad), (0, 0)))
             ip = jnp.pad(ip, ((0, 0), (0, g_pad), (0, 0)))
             xp = _pad_to(xp, (g + g_pad) * m_group, 1)
-        fn = (_nm.nm_gather_seq_policy_matmul if impl == "gather"
-              else _nm.nm_seq_policy_matmul)
-        out = fn(
-            xp, vp, ip, policy=policy, acc_bits=acc_bits, rounds=rounds,
-            m_group=m_group, bm=bm, bn=bn, bg=bg, interpret=interpret,
-        )
+        if impl == "gather":
+            out = _nm.nm_gather_seq_policy_matmul(
+                xp, vp, ip, policy=policy, acc_bits=acc_bits, rounds=rounds,
+                m_group=m_group, bm=bm, bn=bn, bg=bg, interpret=interpret,
+            )
+        else:
+            out = _nm.nm_seq_policy_matmul(
+                _as_int8(xp), vp, ip, policy=policy, acc_bits=acc_bits,
+                rounds=rounds, m_group=m_group, bm=bm, bn=bn, bg=bg,
+                k_tile=k_tile, interpret=interpret,
+            )
     return out[:m, :n]
 
 
@@ -647,7 +698,8 @@ def quant_matmul(x, w, *, bm=128, bn=128, bk=512, interpret=None):
     m, n = x.shape[0], w.shape[1]
     xp = _pad_to(_pad_to(x, bm, 0), bk, 1)
     wp = _pad_to(_pad_to(w, bk, 0), bn, 1)
-    out = _qm.quant_matmul(xp, wp, bm=bm, bn=bn, bk=bk, interpret=interpret)
+    out = _qm.quant_matmul(_as_int8(xp), _as_int8(wp), bm=bm, bn=bn, bk=bk,
+                           interpret=interpret)
     return out[:m, :n]
 
 
@@ -676,20 +728,12 @@ def clip_matmul(x, w, *, acc_bits=16, bm=None, bn=None, bk=256,
 def nm_spmm(
     x, values, indices, *, m_group=16, bm=128, bn=128, bg=32, interpret=None
 ):
-    """Compressed N:M matmul: (M,K) x [(N,G,keep) vals+idx] -> (M,N) int32."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    m, n = x.shape[0], values.shape[0]
-    xp = _pad_to(_pad_to(x, bm, 0), bg * m_group, 1)
-    g_pad = (-values.shape[1]) % bg
-    if g_pad:
-        values = jnp.pad(values, ((0, 0), (0, g_pad), (0, 0)))
-        indices = jnp.pad(indices, ((0, 0), (0, g_pad), (0, 0)))
-    vp = _pad_to(values, bn, 0)
-    ip = _pad_to(indices, bn, 0)
-    out = _nm.nm_spmm(
-        xp, vp, ip, m_group=m_group, bm=bm, bn=bn, bg=bg, interpret=interpret
+    """Compressed N:M matmul: (M,K) x [(N,G,keep) vals+idx] -> (M,N) int32,
+    the exact ``wide`` policy on the expand kernel."""
+    return nm_policy_matmul(
+        x, values, indices, m_group=m_group, policy="wide", bm=bm, bn=bn,
+        bg=bg, nm_impl="expand", interpret=interpret,
     )
-    return out[:m, :n]
 
 
 def compress_nm_weights(w: np.ndarray, n_keep: int, m: int):

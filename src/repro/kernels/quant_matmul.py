@@ -17,17 +17,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.sorted_matmul import int_dot
 
-def _kernel(x_ref, w_ref, o_ref):
+
+def _kernel(x_ref, w_ref, o_ref, *, interpret: bool):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    xb = x_ref[...].astype(jnp.int32)
-    wb = w_ref[...].astype(jnp.int32)
-    o_ref[...] += jax.lax.dot_general(
-        xb, wb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
-    )
+    o_ref[...] += int_dot(x_ref[...], w_ref[...], interpret)
 
 
 @functools.partial(
@@ -48,7 +46,7 @@ def quant_matmul(
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, (m, n, k, bm, bn, bk)
     grid = (m // bm, n // bn, k // bk)
     return pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, interpret=interpret),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

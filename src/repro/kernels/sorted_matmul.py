@@ -15,19 +15,19 @@ every accumulation policy of ``core.overflow``:
                      (this repo's beyond-paper refinement)
 
 ``seq_policy_matmul`` streams K through the grid (k innermost, output
-block revisited — the blocked-matmul-compatible form); ``sort_matmul``
-keeps the full K axis VMEM-resident because its accumulation order is a
-global permutation of K. The sort itself is vectorized over the (bm, bn)
-output block on the VPU.
+block revisited — the blocked-matmul-compatible form) and compiles for
+TPU: int8 operands feed the MXU dot, and the order-sensitive policies
+build the (bk, bm, bn) partial-product cube with K on the leading axis,
+so the sort and the stepwise walk only ever index that axis statically.
+The sort itself is vectorized over the (bm, bn) output block on the VPU.
 
-VMEM budget: the (bm, bn, bk) partial-product cube dominates at
-bm*bn*bk*4 bytes — default (8, 128, 256) = 1 MiB, inside v5e's VMEM
-alongside the x/w slabs. ``sort_matmul`` is the *legacy one-pass* form
-of the global-permutation policies (bk = the whole padded K, cube fully
-resident): ``kernels/ops.policy_matmul`` uses it up to
-``ops.MAX_RESIDENT_K`` and routes larger K to the two-pass streaming
-pipeline in ``kernels/sorted_stream.py``, which bounds VMEM by the int8
-operand slabs instead of the cube (``ops.MAX_STREAM_K``).
+``sort_matmul`` keeps the full K axis VMEM-resident because its
+accumulation order is a global permutation of K. It is the *legacy
+one-pass* form of the global-permutation policies (the whole padded K
+as a (bm, bn, K) cube, pairing by gather): ``kernels/ops.policy_matmul``
+uses it up to ``ops.MAX_RESIDENT_K`` and routes larger K to the two-pass
+streaming pipeline in ``kernels/sorted_stream.py``. Neither compiles for
+TPU; both run in interpret mode only (``ops`` refuses them on a chip).
 
 Semantics are bit-exact with the pure-jnp oracles (``ref.py`` /
 ``core.overflow.accumulate``): stepwise saturation, not cumsum-then-clip,
@@ -53,49 +53,85 @@ SEQ_POLICIES = ("wide", "clip", "wrap", "sorted_tiled_seq")
 SORT_POLICIES = ("sorted", "sorted_tiled")
 
 
+# Longest K run ``_stepwise`` unrolls. Compiled kernels index their K
+# slices statically (Mosaic has no dynamic_slice) and stream at most one
+# block of K per grid step; only the interpret-mode global-sort kernels
+# hand it whole-K cubes, which loop instead.
+_UNROLL = 1024
+
+
 def _stepwise(ordered: jax.Array, init: jax.Array, acc_bits: int,
               saturate: bool) -> jax.Array:
-    """Accumulate (bm, bn, k) values into (bm, bn) p-bit registers, one
-    saturating/wrapping add per step — mirrors monotone_accumulate."""
+    """Accumulate (k, bm, bn) values — K on the LEADING axis — into
+    (bm, bn) p-bit registers, one saturating/wrapping add per step
+    (mirrors monotone_accumulate)."""
     qmin, qmax = qrange(acc_bits)
-    span = jnp.int32(2**acc_bits)
+    mask = 2**acc_bits - 1
 
-    def body(t, acc):
-        nxt = acc + ordered[:, :, t]
+    def step(acc, p):
+        nxt = acc + p
         if saturate:
             return jnp.clip(nxt, qmin, qmax)
-        return jnp.mod(nxt - qmin, span) + qmin
+        return ((nxt - qmin) & mask) + qmin  # two's-complement wrap
 
-    return jax.lax.fori_loop(0, ordered.shape[-1], body, init)
+    if ordered.shape[0] <= _UNROLL:
+        for t in range(ordered.shape[0]):
+            init = step(init, ordered[t])
+        return init
+    return jax.lax.fori_loop(
+        0, ordered.shape[0], lambda t, acc: step(acc, ordered[t]), init)
 
 
-def _seq_body(xb, wb, o_ref, *, policy: str, acc_bits: int, rounds: int):
-    """One K-streaming grid step on int32 blocks xb (bm, bk) / wb
-    (bn, bk). THE single definition of the seq-policy semantics — the
-    dense kernel and the N:M compressed kernel (kernels/nm_spmm.py)
-    differ only in how wb reaches VMEM, so a semantics change here
-    cannot desynchronize the two storage forms."""
+def int_dot(a: jax.Array, b: jax.Array, interpret: bool) -> jax.Array:
+    """(m, k) x (k, n) int8 -> int32. Compiled, the int8 operands feed the
+    MXU (Mosaic has no int32 x int32 dot); interpreted, they widen to
+    int32 first, because XLA:CPU miscompiles some small int8 dots."""
+    if interpret:
+        a, b = a.astype(jnp.int32), b.astype(jnp.int32)
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.int32)
+
+
+def _seq_body(xb, wt, o_ref, *, policy: str, acc_bits: int, rounds: int,
+              k_tile: int, interpret: bool, cols=None):
+    """One K-streaming grid step on int8 blocks xb (bm, bk) / wt (bk, bn).
+    THE single definition of the seq-policy semantics — the dense kernel
+    and the N:M compressed kernel (kernels/nm_spmm.py) differ only in how
+    wt reaches VMEM, so a semantics change here cannot desynchronize the
+    two storage forms.
+
+    ``cols[t]`` is the column of xb (and row of wt) holding natural K
+    offset t of the block (identity when None); only the order-sensitive
+    policies read it. The partial products are built one K slice at a
+    time as (bm, bn) tiles stacked on the leading axis, so the sort and
+    the stepwise walk index that axis statically; a block holds
+    ``bk // k_tile`` independent sort tiles.
+    """
     if policy == "wide":
-        o_ref[...] += jax.lax.dot_general(
-            xb, wb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
+        o_ref[...] += int_dot(xb, wt, interpret)
         return
-    prods = xb[:, None, :] * wb[None, :, :]  # (bm, bn, bk) partial products
+    x32, w32 = xb.astype(jnp.int32), wt.astype(jnp.int32)
+    cols = range(xb.shape[1]) if cols is None else cols
+    prods = jnp.stack([x32[:, c:c + 1] * w32[c:c + 1, :] for c in cols])
+    acc = o_ref[...]
     if policy == "sorted_tiled_seq":
-        prods = sorted_order_bitonic(prods, rounds)  # sort stage (VPU)
-    o_ref[...] = _stepwise(prods, o_ref[...], acc_bits,
-                           saturate=(policy != "wrap"))
+        for s in range(0, prods.shape[0], k_tile):
+            tile = sorted_order_bitonic(prods[s:s + k_tile], rounds, axis=0)
+            acc = _stepwise(tile, acc, acc_bits, saturate=True)
+    else:
+        acc = _stepwise(prods, acc, acc_bits, saturate=(policy != "wrap"))
+    o_ref[...] = acc
 
 
 def _seq_kernel(x_ref, w_ref, o_ref, *, policy: str, acc_bits: int,
-                rounds: int):
+                rounds: int, k_tile: int, interpret: bool):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    _seq_body(x_ref[...].astype(jnp.int32), w_ref[...].astype(jnp.int32),
-              o_ref, policy=policy, acc_bits=acc_bits, rounds=rounds)
+    _seq_body(x_ref[...], w_ref[...], o_ref, policy=policy,
+              acc_bits=acc_bits, rounds=rounds, k_tile=k_tile,
+              interpret=interpret)
 
 
 def _sort_body(xb, wb, o_ref, *, policy: str, acc_bits: int, k_tile: int,
@@ -108,8 +144,8 @@ def _sort_body(xb, wb, o_ref, *, policy: str, acc_bits: int, k_tile: int,
     else:  # sorted_tiled: shared pairing permutation, bitonic intra-tile
         ordered = tiled_sorted_order(prods, k_tile, rounds,
                                      order_fn=sorted_order_bitonic)
-    o_ref[...] = _stepwise(ordered, jnp.zeros_like(o_ref), acc_bits,
-                           saturate=True)
+    o_ref[...] = _stepwise(jnp.moveaxis(ordered, -1, 0),
+                           jnp.zeros_like(o_ref), acc_bits, saturate=True)
 
 
 def _sort_kernel(x_ref, w_ref, o_ref, *, policy: str, acc_bits: int,
@@ -122,11 +158,11 @@ def _sort_kernel(x_ref, w_ref, o_ref, *, policy: str, acc_bits: int,
 @functools.partial(
     jax.jit,
     static_argnames=("policy", "acc_bits", "rounds", "bm", "bn", "bk",
-                     "interpret"),
+                     "k_tile", "interpret"),
 )
 def seq_policy_matmul(
-    x: jax.Array,  # (M, K) int8/int32-carrier activations
-    w: jax.Array,  # (N, K) weights (rows = output channels)
+    x: jax.Array,  # (M, K) int8 activations
+    w: jax.Array,  # (N, K) int8 weights (rows = output channels)
     *,
     policy: str = "clip",
     acc_bits: int = 16,
@@ -134,35 +170,39 @@ def seq_policy_matmul(
     bm: int = 8,
     bn: int = 128,
     bk: int = 256,
+    k_tile: int = 256,
     interpret: bool = False,
 ) -> jax.Array:
     """K-streaming policies: wide | clip | wrap | sorted_tiled_seq.
 
-    For sorted_tiled_seq, bk IS the paper's k_tile (the sort never sees
-    across a block boundary) and must be a power of two for the bitonic
-    network.
+    The weights stream K-major, (bk, bn) blocks of w.T, so a K slice of
+    the block is a sublane row. For sorted_tiled_seq the sort never
+    sees across a k_tile boundary; k_tile must be a power of two that
+    divides bk (a 128-lane block holds several smaller tiles).
     """
     m, k = x.shape
     n, k2 = w.shape
     assert k == k2, (x.shape, w.shape)
+    assert x.dtype == w.dtype == jnp.int8, (x.dtype, w.dtype)
     assert policy in SEQ_POLICIES, policy
     if policy == "sorted_tiled_seq":
-        assert bk & (bk - 1) == 0, f"bk must be a power of 2, got {bk}"
+        assert k_tile & (k_tile - 1) == 0 and bk % k_tile == 0, (bk, k_tile)
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, (m, n, k, bm, bn, bk)
     grid = (m // bm, n // bn, k // bk)
     kern = functools.partial(_seq_kernel, policy=policy, acc_bits=acc_bits,
-                             rounds=rounds)
+                             rounds=rounds, k_tile=k_tile,
+                             interpret=interpret)
     return pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bn, bk), lambda i, j, kk: (j, kk)),
+            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
-    )(x, w)
+    )(x, w.T)
 
 
 @functools.partial(
@@ -212,39 +252,3 @@ def sort_matmul(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
     )(x, w)
-
-
-def sorted_matmul(
-    x: jax.Array,  # (M, K) int8 activations
-    w: jax.Array,  # (N, K) int8 weights (rows = output channels)
-    *,
-    acc_bits: int = 16,
-    rounds: int = 1,
-    bm: int = 8,
-    bn: int = 128,
-    bk: int = 256,
-    interpret: bool = False,
-) -> jax.Array:
-    """(M, N) int32 carrier holding acc_bits-bit saturated dot products
-    under the sorted_tiled_seq policy (bk = k_tile)."""
-    return seq_policy_matmul(
-        x, w, policy="sorted_tiled_seq", acc_bits=acc_bits, rounds=rounds,
-        bm=bm, bn=bn, bk=bk, interpret=interpret,
-    )
-
-
-def clip_matmul(
-    x: jax.Array,
-    w: jax.Array,
-    *,
-    acc_bits: int = 16,
-    bm: int = 8,
-    bn: int = 128,
-    bk: int = 256,
-    interpret: bool = False,
-) -> jax.Array:
-    """Clipping baseline: natural order, saturating adds."""
-    return seq_policy_matmul(
-        x, w, policy="clip", acc_bits=acc_bits,
-        bm=bm, bn=bn, bk=bk, interpret=interpret,
-    )

@@ -201,15 +201,16 @@ def _paired_body(xb, wb, pm, o_ref, acc_bits: int, k_tile: int,
         pa = sorted_order_bitonic(pa, rounds)
         pb = sorted_order_bitonic(pb, rounds)
         inter = jnp.stack([pa, pb], axis=-1).reshape(bm, bn, 2 * k_tile)
-        return _stepwise(inter, acc, acc_bits, saturate=True)
+        return _stepwise(jnp.moveaxis(inter, -1, 0), acc, acc_bits,
+                         saturate=True)
 
     acc = jax.lax.fori_loop(
         0, n_tiles // 2, slot, jnp.zeros_like(o_ref)
     )
     if n_tiles % 2:  # unpaired leftover tile rides last, un-interleaved
         tail = _gather_tile(xb, wb, pm[:, :, n_tiles - 1], k_tile)
-        acc = _stepwise(sorted_order_bitonic(tail, rounds), acc, acc_bits,
-                        saturate=True)
+        tail = jnp.moveaxis(sorted_order_bitonic(tail, rounds), -1, 0)
+        acc = _stepwise(tail, acc, acc_bits, saturate=True)
     o_ref[...] = acc
 
 
@@ -324,8 +325,8 @@ def _sort_chunk_body(xb, wb, o_ref, c, bc, acc_bits: int, rounds: int):
     prods = xb[:, None, :] * wb[None, :, :]  # (bm, bc, K) live chunk
     ordered = sorted_order_bitonic(prods, rounds)
     o_ref[:, pl.ds(c * bc, bc)] = _stepwise(
-        ordered, jnp.zeros((xb.shape[0], bc), jnp.int32), acc_bits,
-        saturate=True,
+        jnp.moveaxis(ordered, -1, 0), jnp.zeros((xb.shape[0], bc), jnp.int32),
+        acc_bits, saturate=True,
     )
 
 
@@ -626,12 +627,14 @@ def _nm_gather_paired_kernel(x_ref, v_ref, i_ref, p_ref, o_ref, *,
         pa = sorted_order_bitonic(ctile(pm[:, :, 2 * s]), rounds)
         pb = sorted_order_bitonic(ctile(pm[:, :, 2 * s + 1]), rounds)
         inter = jnp.stack([pa, pb], axis=-1).reshape(bm, bn, 2 * lp)
-        return _stepwise(inter, acc, acc_bits, saturate=True)
+        return _stepwise(jnp.moveaxis(inter, -1, 0), acc, acc_bits,
+                         saturate=True)
 
     acc = jax.lax.fori_loop(0, n_tiles // 2, slot, jnp.zeros_like(o_ref))
     if n_tiles % 2:  # unpaired leftover tile rides last, un-interleaved
         tail = sorted_order_bitonic(ctile(pm[:, :, n_tiles - 1]), rounds)
-        acc = _stepwise(tail, acc, acc_bits, saturate=True)
+        acc = _stepwise(jnp.moveaxis(tail, -1, 0), acc, acc_bits,
+                        saturate=True)
     o_ref[...] = acc
 
 
@@ -697,8 +700,8 @@ def _nm_gather_chunked_sort_kernel(x_ref, v_ref, i_ref, o_ref, *,
         prods = gather_nm_products(xb, vc, ic, m_group)
         ordered = sorted_order_bitonic(pad_last_pow2(prods), rounds)
         o_ref[:, pl.ds(c * bc, bc)] = _stepwise(
-            ordered, jnp.zeros((xb.shape[0], bc), jnp.int32), acc_bits,
-            saturate=True,
+            jnp.moveaxis(ordered, -1, 0),
+            jnp.zeros((xb.shape[0], bc), jnp.int32), acc_bits, saturate=True,
         )
         return 0
 

@@ -192,7 +192,7 @@ def run_cell(
     ins, outs, args = _shardings_for(mesh, model, kind, shape,
                                      quantized=quantized)
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             step, in_shardings=ins, out_shardings=outs, donate_argnums=donate
         )

@@ -23,33 +23,30 @@ import jax
 
 def make_abstract_mesh(
     shape: Sequence[int], axes: Sequence[str]
-) -> "jax.sharding.AbstractMesh":
-    """Device-free mesh for sharding-rule evaluation, across jax versions.
+) -> jax.sharding.AbstractMesh:
+    """Device-free mesh for sharding-rule evaluation."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
-    jax <= 0.4.x wants ``AbstractMesh(((name, size), ...))`` — a tuple of
-    (name, size) pairs; newer jax takes ``AbstractMesh(shape, axes)``.
-    Passing a bare shape tuple to the old signature raises
-    ``TypeError: 'int' object is not iterable``, so construction is
-    centralized here.
-    """
-    from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        return AbstractMesh(tuple(shape), tuple(axes))
+def _mesh(shape: Sequence[int], axes: Sequence[str]) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with ``Auto`` axes: the sharding hints
+    (``models.hints``) and the ``shard_map`` specs name mesh axes that
+    GSPMD propagates, which ``Explicit`` axes (the make_mesh default)
+    refuse in ``with_sharding_constraint``."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """Degenerate mesh over the real local devices (tests / examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _mesh((n, 1), ("data", "model"))
 
 
 def make_host_serve_mesh(model_parallel: Optional[int] = None
@@ -65,7 +62,7 @@ def make_host_serve_mesh(model_parallel: Optional[int] = None
     tp = model_parallel or (n if n % 2 or n < 4 else n // 2)
     if n % tp:
         raise ValueError(f"model_parallel={tp} does not divide {n} devices")
-    return jax.make_mesh((n // tp, tp), ("data", "model"))
+    return _mesh((n // tp, tp), ("data", "model"))
 
 
 def shrink_serve_mesh(

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model, param_count
 from repro.serving import CensusWatch, Request, ServingEngine, ServingFleet
 
@@ -92,6 +93,7 @@ def main() -> None:
                     help="accumulator-aware fine-tuning steps before "
                          "quantization (runtime.a2q_finetune; 0 = skip)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
@@ -134,12 +136,14 @@ def main() -> None:
         else:
             from repro.core.qtensor import quantize_tree
 
-            params = quantize_tree(
-                params, bits=8, min_size=1 << 10, min_dim=16
-            )
+            # smoke widths need low thresholds to count as matrices; at
+            # full width they would take layer-stacked (L, out) biases
+            # for matrices once L >= 16
+            small = dict(min_size=1 << 10, min_dim=16) if args.smoke else {}
+            params = quantize_tree(params, bits=8, **small)
         int_lin = dispatch.IntegerLinConfig(
             policy=args.int_policy, acc_bits=args.acc_bits,
-            k_tile=64, backend="jnp", certificate=cert,
+            k_tile=64, certificate=cert,
         )
         if args.census_threshold is not None:
             census_watch = CensusWatch(

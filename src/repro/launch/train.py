@@ -65,7 +65,7 @@ def main() -> None:
     o_shapes = jax.eval_shape(opt.init, p_shapes)
     o_shard = shard_lib.opt_shardings(mesh, o_shapes)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params = jax.jit(model.init, out_shardings=p_shard)(
             jax.random.PRNGKey(0)
         )
@@ -106,7 +106,7 @@ def main() -> None:
 
     monitor = StragglerMonitor()
     times = []
-    with mesh:
+    with jax.set_mesh(mesh):
         for step in range(start, args.steps):
             t0 = time.perf_counter()
             batch = {k: jnp.asarray(v) for k, v in data.next_batch().items()}

@@ -5,8 +5,8 @@ embedding gathers, scans, and remat (§Perf iteration 1 found the batch
 axis silently replicated mid-graph, turning TP matmuls into full-batch
 f32 all-reduces). These hints pin the residual-stream layout at every
 layer boundary. They are exact no-ops when no mesh is active (unit tests,
-single-device examples) and filter axis names against the ambient mesh,
-so the same model code runs everywhere.
+single-device examples) and filter axis names against the ambient mesh
+(``jax.set_mesh``), so the same model code runs everywhere.
 """
 
 from __future__ import annotations
@@ -18,15 +18,9 @@ from jax.sharding import PartitionSpec as P
 
 
 def _ambient_mesh():
-    try:
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
-    return None
+    """The mesh set by ``jax.set_mesh``, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def _filter(mesh, entry: Any, dim: int) -> Any:

@@ -38,25 +38,14 @@ def default_retryable() -> tuple[type[BaseException], ...]:
     """Exception types a supervisor treats as recoverable node failures.
 
     Device loss / runtime aborts surface from jax as
-    ``jaxlib.xla_extension.XlaRuntimeError``. On current jaxlib that class
-    subclasses RuntimeError so the plain default already covers it, but
-    the subclassing is not contractual — list it explicitly so the
-    default survives a jaxlib that moves it off RuntimeError.
+    ``jax.errors.JaxRuntimeError``. It subclasses RuntimeError today, so
+    the plain default already covers it, but the subclassing is not
+    contractual — list it explicitly so the default survives a jax that
+    moves it off RuntimeError.
     """
-    types: list[type[BaseException]] = [RuntimeError]
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
+    from jax.errors import JaxRuntimeError
 
-        types.append(XlaRuntimeError)
-    except ImportError:
-        pass
-    try:
-        from jax.errors import JaxRuntimeError
-
-        types.append(JaxRuntimeError)
-    except ImportError:
-        pass
-    return tuple(dict.fromkeys(types))
+    return tuple(dict.fromkeys((RuntimeError, JaxRuntimeError)))
 
 
 class FailureInjector:

@@ -187,6 +187,13 @@ class ServingEngine:
         if mesh is not None and int_lin is not None:
             # distribute the integer projections over the serving mesh
             int_lin = dataclasses.replace(int_lin, mesh=mesh)
+        if mesh is not None:
+            # every member holds the whole tree (the projections'
+            # shard_map slices it per device); params committed to one
+            # device would otherwise be refused by the meshed step
+            params = jax.device_put(
+                params, jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+            )
         quantized = (
             cache_dtype == "int8"
             if isinstance(cache_dtype, str)
@@ -365,7 +372,11 @@ class ServingEngine:
             fwd = jax.jit(lambda p, b: self.model.forward(p, b))
             for batch in batches:
                 jax.block_until_ready(fwd(self.params, batch))
-        frozen = cal.freeze(bits=act_bits, symmetric=symmetric)
+        # the observer ran inside host callbacks, which JAX runs on its
+        # CPU device: take the frozen scales as host values, or they would
+        # pin every later step to the CPU
+        frozen = jax.tree_util.tree_map(
+            np.asarray, cal.freeze(bits=act_bits, symmetric=symmetric))
         self.params = attach_act_qparams(self.params, frozen)
         return frozen
 

@@ -1,6 +1,10 @@
 """Per-kernel validation: shape/dtype sweeps vs the pure-jnp oracles
 (interpret mode executes the kernel bodies on CPU)."""
 
+import os
+import subprocess
+import sys
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -205,3 +209,38 @@ def test_nm_spmm_bandwidth_model():
     idx_bytes = n * (k // m) * n_keep  # int8-packable positions (< m = 16)
     assert vals_bytes == dense_bytes * n_keep / m
     assert (vals_bytes + idx_bytes) <= dense_bytes / 2
+
+
+@pytest.mark.parametrize("policy", ["sorted", "sorted_tiled"])
+def test_global_sort_kernels_refused_when_compiled(policy):
+    """The global-sort kernels have no compiled TPU form: a compiled call
+    raises instead of falling back to jnp or the interpreter."""
+    x = jnp.ones((8, 256), jnp.int8)
+    w = jnp.ones((128, 256), jnp.int8)
+    with pytest.raises(ValueError, match="no compiled TPU kernel"):
+        ops.policy_matmul(x, w, policy=policy, interpret=False)
+    vals, idx = ops.compress_nm_weights(np.ones((128, 256), np.int8), 4, 4)
+    with pytest.raises(ValueError, match="no compiled TPU kernel"):
+        ops.nm_policy_matmul(x, vals, idx, m_group=4, policy=policy,
+                             interpret=False)
+
+
+def test_nm_gather_never_chosen_when_compiled(monkeypatch):
+    """``auto`` takes the gather kernels only in interpret mode; asking
+    for them on a compiled path is refused."""
+    monkeypatch.delenv("REPRO_PQS_NM_IMPL", raising=False)
+    assert ops.resolve_nm_impl("sorted_tiled_seq", 64, 2, 4) == "gather"
+    assert ops.resolve_nm_impl("sorted_tiled_seq", 64, 2, 4,
+                               compiled=True) == "expand"
+    with pytest.raises(ValueError, match="does not compile"):
+        ops.resolve_nm_impl("clip", 64, 2, 4, "gather", compiled=True)
+
+
+def test_kernel_layer_imports_on_its_own():
+    """``repro.kernels.ops`` loads first in a fresh process: no import
+    cycle through ``repro.core``."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=os.path.abspath(src),
+               JAX_PLATFORMS="cpu")
+    subprocess.run([sys.executable, "-c", "import repro.kernels.ops"],
+                   check=True, env=env, timeout=120)

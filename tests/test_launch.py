@@ -157,7 +157,7 @@ def test_train_steps_lower_on_host_mesh():
     o = make_opt_specs(model)
     b = {"tokens": jax.ShapeDtypeStruct((4, 32), jnp.int32),
          "labels": jax.ShapeDtypeStruct((4, 32), jnp.int32)}
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step).lower(p, o, b)
         assert lowered.cost_analysis().get("flops", 0) > 0
 
@@ -179,3 +179,26 @@ def test_dryrun_subprocess_smallest_cell():
     assert len(data["results"]) == 1 and not data["failures"]
     cell = data["results"][0]
     assert cell["memory"]["peak_bytes"] < 16e9  # fits v5e HBM
+
+
+def test_compile_cache_follows_env_else_fixed_repo_path(monkeypatch):
+    from repro.launch import compile_cache
+
+    repo = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    assert str(compile_cache.REPO_CACHE_DIR) == os.path.join(repo, ".jax_cache")
+    saved = (jax.config.jax_compilation_cache_dir,
+             jax.config.jax_persistent_cache_min_compile_time_secs)
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = compile_cache.enable_compile_cache()
+        assert path == str(compile_cache.REPO_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == path
+        # a set variable is JAX's own setting: no other directory is set
+        jax.config.update("jax_compilation_cache_dir", saved[0])
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == saved[0]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          saved[1])

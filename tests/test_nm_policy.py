@@ -28,6 +28,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 
 from repro.core.dispatch import IntegerLinConfig, pqs_dot, qtensor_dot  # noqa: E402
 from repro.core.pruning import (  # noqa: E402
@@ -256,7 +257,8 @@ def test_nm_sharded_bit_identical(policy):
     M, K, N = 5, 128, 6  # N=6 does not divide the model axis -> degrade
     vals, idx, dense = _compressed(N, K, n_keep, m, seed=17)
     x = _x(M, K, seed=17)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     ref = pqs_dot(x, dense, acc_bits=14, policy=policy, k_tile=32,
                   backend="jnp")
     out = pqs_dot(x, (vals, idx), storage="nm", m_group=m, acc_bits=14,
@@ -419,7 +421,8 @@ def test_nm_gather_sharded_k_axis():
     M, K, N = 4, 512, 6
     vals, idx, dense = _compressed(N, K, n_keep, m, seed=41)
     x = _x(M, K, seed=41)
-    mesh = jax.make_mesh((2, 2, 2), ("data", "model", "kdim"))
+    mesh = jax.make_mesh((2, 2, 2), ("data", "model", "kdim"),
+                         axis_types=(AxisType.Auto,) * 3)
     for policy in ("clip", "sorted_tiled"):
         ref = pqs_dot(x, (vals, idx), storage="nm", m_group=m, acc_bits=14,
                       policy=policy, k_tile=32, backend="jnp",
@@ -481,7 +484,8 @@ def test_nm_assert_canonical_catches_violations():
 def test_nm_sharded_census_counts_once():
     vals, idx, dense = _compressed(10, 200, 4, 8, seed=19)
     x = _x(6, 200, seed=19)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     _, ref = pqs_dot(x, dense, acc_bits=16, policy="clip", backend="jnp",
                      with_census=True)
     _, out = pqs_dot(x, (vals, idx), storage="nm", m_group=8, acc_bits=16,

@@ -35,6 +35,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 
 if len(jax.devices()) < 2:
     pytest.skip(
@@ -54,7 +55,8 @@ SHAPES = ((8, 300, 6), (5, 128, 16), (4, 96, 8))
 
 
 def _mesh(data, model):
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def _xw(m, k, n, seed=0):
@@ -122,7 +124,8 @@ CENSUS_FIELDS = ("n_dots", "n_persistent", "n_transient", "n_any",
 
 
 def _mesh3(data, model, k):
-    return jax.make_mesh((data, model, k), ("data", "model", "k"))
+    return jax.make_mesh((data, model, k), ("data", "model", "k"),
+                         axis_types=(AxisType.Auto,) * 3)
 
 
 @pytest.mark.parametrize("policy", POLICIES)
