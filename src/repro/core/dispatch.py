@@ -832,17 +832,22 @@ def pqs_dot(
         storage=storage, m_group=m_group if storage == "nm" else None,
         nm_impl=nm_impl if storage == "nm" else None, certified=certified,
     )
+    # the kernels' device time reads, on a profile, under this scope
+    scope = jax.named_scope(
+        f"pqs_dot.{policy}" + (".certified" if certified else ""))
     if defer_combine:
         if mesh is not None and k_axis is not None:
-            pending = _sharded_dot(
-                x2, w, mesh, m_axes, n_axis, with_census, k_axis=k_axis,
-                defer=True, **kw
-            )
+            with scope:
+                pending = _sharded_dot(
+                    x2, w, mesh, m_axes, n_axis, with_census, k_axis=k_axis,
+                    defer=True, **kw
+                )
         elif mesh is None and k_shards > 1:
-            pending = _kshard_dot(
-                x2, w, k_shards=k_shards, with_census=with_census,
-                defer=True, **kw
-            )
+            with scope:
+                pending = _kshard_dot(
+                    x2, w, k_shards=k_shards, with_census=with_census,
+                    defer=True, **kw
+                )
         else:
             raise ValueError(
                 "defer_combine=True needs a K-sharded dot "
@@ -856,17 +861,19 @@ def pqs_dot(
 
         return PendingCombine(pending.partials, finish_full)
 
-    if mesh is not None:
-        res = _sharded_dot(
-            x2, w, mesh, m_axes, n_axis, with_census, k_axis=k_axis, **kw
-        )
-        out, tot = res if with_census else (res, None)
-    elif k_shards > 1:
-        out, tot = _kshard_dot(
-            x2, w, k_shards=k_shards, with_census=with_census, **kw
-        )
-    else:
-        out, tot = _local_dot(x2, w, with_census=with_census, **kw)
+    with scope:
+        if mesh is not None:
+            res = _sharded_dot(
+                x2, w, mesh, m_axes, n_axis, with_census, k_axis=k_axis,
+                **kw
+            )
+            out, tot = res if with_census else (res, None)
+        elif k_shards > 1:
+            out, tot = _kshard_dot(
+                x2, w, k_shards=k_shards, with_census=with_census, **kw
+            )
+        else:
+            out, tot = _local_dot(x2, w, with_census=with_census, **kw)
     out = out.reshape(*lead, n)
     if with_census:
         return out, tot

@@ -50,9 +50,10 @@ def cast_for_compute(params: Any, cfg: ModelConfig) -> Any:
             return leaf.astype(dt)
         return leaf
 
-    return jax.tree_util.tree_map(
-        conv, params, is_leaf=lambda l: isinstance(l, QTensor)
-    )
+    with jax.named_scope("cast"):
+        return jax.tree_util.tree_map(
+            conv, params, is_leaf=lambda l: isinstance(l, QTensor)
+        )
 from repro.models.transformer import lm_loss
 
 

@@ -202,27 +202,32 @@ def prefill_step(
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     if cfg.mrope_sections is not None:
         positions = jnp.broadcast_to(positions, (3, b, s))
-    x = hint_batch(embed_tokens(params, tokens, cfg))
+    with jax.named_scope("embed"):
+        x = hint_batch(embed_tokens(params, tokens, cfg))
 
-    window = cfg.sliding_window
     wins = layer_windows(cfg)
     flags = is_local_flags(cfg)
     homogeneous = all(w == wins[0] for w in wins)
 
     def one_layer(p, x, cache, flag, win):
-        h, (k, v) = attention(
-            p["attn"], norm(x, p["ln1"], cfg), positions, cfg,
-            causal=True, window=win, use_window=flag, return_kv=True,
-        )
-        x = x + h
-        if cfg.moe is not None:
-            # per-token routing: identical capacity situation to decode,
-            # so prefill never capacity-drops a token decode would keep
-            h, _ = moe_lib.moe_ffn_per_token(
-                p["moe"], norm(x, p["ln2"], cfg), cfg, cfg.moe)
-        else:
-            h = mlp(p["mlp"], norm(x, p["ln2"], cfg), cfg)
-        return x + h, write_prefill_kv(cache, k, v, lengths)
+        with jax.named_scope("attn"):
+            h, (k, v) = attention(
+                p["attn"], norm(x, p["ln1"], cfg), positions, cfg,
+                causal=True, window=win, use_window=flag, return_kv=True,
+            )
+            x = x + h
+            cache = write_prefill_kv(cache, k, v, lengths)
+        with jax.named_scope("mlp"):
+            if cfg.moe is not None:
+                # per-token routing: identical capacity situation to
+                # decode, so prefill never capacity-drops a token decode
+                # would keep
+                h, _ = moe_lib.moe_ffn_per_token(
+                    p["moe"], norm(x, p["ln2"], cfg), cfg, cfg.moe)
+            else:
+                h = mlp(p["mlp"], norm(x, p["ln2"], cfg), cfg)
+            x = x + h
+        return x, cache
 
     if homogeneous:
         def body(x, inp):
@@ -230,17 +235,21 @@ def prefill_step(
             x, new_cache = one_layer(p, x, cache, flag, wins[0])
             return hint_batch(x), new_cache
 
-        x, new_caches = jax.lax.scan(
-            body, x, (params["layers"], flags, caches),
-            unroll=cfg.scan_unroll,
-        )
+        # the scan's own per-layer slicing of weights and caches
+        with jax.named_scope("layers"):
+            x, new_caches = jax.lax.scan(
+                body, x, (params["layers"], flags, caches),
+                unroll=cfg.scan_unroll,
+            )
     else:
         new_caches = []
         for i, win in enumerate(wins):
             p = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
             x, nc = one_layer(p, x, caches[i], flags[i], win)
             new_caches.append(nc)
-    return hint_logits(logits_from_hidden(params, x, cfg)), new_caches
+    with jax.named_scope("head"):
+        logits = hint_logits(logits_from_hidden(params, x, cfg))
+    return logits, new_caches
 
 
 # ---------------------------------------------------------------------------
@@ -283,20 +292,25 @@ def decode_step(
     cfg: ModelConfig,
 ) -> tuple[jax.Array, Any]:
     """One decode step; returns (logits (B,1,V), new_caches)."""
-    x = embed_tokens(params, token, cfg)
+    with jax.named_scope("embed"):
+        x = embed_tokens(params, token, cfg)
     wins = layer_windows(cfg)
     homogeneous = all(w == wins[0] for w in wins)
 
     def one_layer(p, x, cache, window):
-        h, new_cache = attention_decode(
-            p["attn"], norm(x, p["ln1"], cfg), cache, cfg, window=window
-        )
-        x = x + h
-        if cfg.moe is not None:
-            h, _ = moe_lib.moe_ffn(p["moe"], norm(x, p["ln2"], cfg), cfg, cfg.moe)
-        else:
-            h = mlp(p["mlp"], norm(x, p["ln2"], cfg), cfg)
-        return x + h, new_cache
+        with jax.named_scope("attn"):
+            h, new_cache = attention_decode(
+                p["attn"], norm(x, p["ln1"], cfg), cache, cfg, window=window
+            )
+            x = x + h
+        with jax.named_scope("mlp"):
+            if cfg.moe is not None:
+                h, _ = moe_lib.moe_ffn(
+                    p["moe"], norm(x, p["ln2"], cfg), cfg, cfg.moe)
+            else:
+                h = mlp(p["mlp"], norm(x, p["ln2"], cfg), cfg)
+            x = x + h
+        return x, new_cache
 
     if homogeneous:
         def body(x, inp):
@@ -304,15 +318,19 @@ def decode_step(
             x, new_cache = one_layer(p, x, cache, wins[0])
             return hint_batch(x), new_cache
 
-        x, new_caches = jax.lax.scan(body, x, (params["layers"], caches),
-                                     unroll=cfg.scan_unroll)
+        # the scan's own per-layer slicing of weights and caches
+        with jax.named_scope("layers"):
+            x, new_caches = jax.lax.scan(body, x, (params["layers"], caches),
+                                         unroll=cfg.scan_unroll)
     else:
         new_caches = []
         for i, w in enumerate(wins):
             p = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
             x, nc = one_layer(p, x, caches[i], w)
             new_caches.append(nc)
-    return hint_logits(logits_from_hidden(params, x, cfg)), new_caches
+    with jax.named_scope("head"):
+        logits = hint_logits(logits_from_hidden(params, x, cfg))
+    return logits, new_caches
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,6 @@ Production framing (DESIGN.md §6), CPU-simulatable components:
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import deque
 from typing import Any, Callable, Optional
 
@@ -135,7 +134,6 @@ class TrainSupervisor:
         # refills — long runs aren't killed by unrelated sporadic faults
         self.reset_after = reset_after
         self.restarts = 0
-        self.step_times: list[float] = []
 
     def run(
         self,
@@ -168,10 +166,8 @@ class TrainSupervisor:
             try:
                 if self.injector is not None:
                     self.injector.maybe_fail(step)
-                t0 = time.perf_counter()
                 batch = next_batch()
                 state, _metrics = self.step_fn(state, batch)
-                self.step_times.append(time.perf_counter() - t0)
                 step += 1
                 clean_steps += 1
                 if self.reset_after and clean_steps >= self.reset_after:

@@ -73,6 +73,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import dispatch
 from repro.models.model import Model
@@ -316,17 +317,19 @@ class ServingEngine:
         def step(params, tok, caches, active):
             with _int_ctx():
                 logits, new_caches = model.decode(params, tok, caches)
-            return logits, model.merge_caches(caches, new_caches, active)
+            with jax.named_scope("merge"):
+                return logits, model.merge_caches(caches, new_caches, active)
 
         def prefill_step(params, toks, caches, lengths, active):
             with _int_ctx():
                 _, new_caches = model.prefill(params, toks, caches, lengths)
             # match cache leaf dtypes (e.g. f32 conv rings fed bf16
             # activations) so merged caches keep the decode signature
-            new_caches = jax.tree_util.tree_map(
-                lambda o, n: n.astype(o.dtype), caches, new_caches
-            )
-            return model.merge_caches(caches, new_caches, active)
+            with jax.named_scope("merge"):
+                new_caches = jax.tree_util.tree_map(
+                    lambda o, n: n.astype(o.dtype), caches, new_caches
+                )
+                return model.merge_caches(caches, new_caches, active)
 
         self._step = jax.jit(step)
         self._prefill_step = jax.jit(prefill_step)
@@ -420,7 +423,7 @@ class ServingEngine:
         self._done_uids.discard(req.uid)
         self.queue.append(req)
 
-    def _admit(self) -> None:
+    def _admit(self) -> list[tuple[int, Request]]:
         """Claim free slots from the queue; reserve + allocate pages.
 
         Paged backpressure: a request only leaves the queue once its
@@ -462,7 +465,7 @@ class ServingEngine:
             )
             admitted.append((slot, req))
         if not admitted:
-            return
+            return admitted
         # clear stale cache lanes (KV pages, SSM state, positions) of
         # the re-used slots; on the paged path the new page tables go
         # live first so the reset zeroes the freshly claimed pages
@@ -475,7 +478,7 @@ class ServingEngine:
             )
         self.caches = self._reset(self.caches, jnp.asarray(mask))
         self._pending.extend(admitted)
-        self._maybe_prefill()
+        return admitted
 
     def _maybe_prefill(self) -> None:
         """Prefill the pending cohort, subject to the interleave budget.
@@ -506,10 +509,12 @@ class ServingEngine:
         first decode step, which produces the first sampled token.
         """
         self.stats["cohorts"] += 1
-        if self.prefill_mode == "batched":
-            self._prefill_batched(admitted)
-        else:
-            self._prefill_steps(admitted)
+        with TraceAnnotation("engine.prefill", step=self._step_idx,
+                             rows=len(admitted)):
+            if self.prefill_mode == "batched":
+                self._prefill_batched(admitted)
+            else:
+                self._prefill_steps(admitted)
         for slot, req in admitted:
             self._next_token[slot, 0] = int(req.prompt[-1])
             self._budget[slot] = req.max_new_tokens
@@ -616,27 +621,50 @@ class ServingEngine:
         admitted-but-pending prefills — 0 means the engine is idle.
         """
         self._step_idx += 1
-        if self.failure_injector is not None:
-            self.failure_injector.maybe_fail(self._step_idx)
-        self._admit()
-        self._maybe_prefill()
-        active = [
-            i for i, r in enumerate(self.slots)
-            if r is not None and self._ready[i]
-        ]
-        if not active:
-            return len(self._pending)
-        if self.paging is not None:
-            self._ensure_decode_pages(active)
-        mask = np.zeros(self.num_slots, bool)
-        mask[active] = True
-        logits, self.caches = self._step(
-            self.params, jnp.asarray(self._next_token), self.caches,
-            jnp.asarray(mask),
-        )
-        self.stats["decode_steps"] += 1
-        self._since_prefill += 1
-        logits = np.asarray(logits.astype(jnp.float32))
+        idx = self._step_idx
+        # one span per phase of the step, never per slot: the step index
+        # rides as a stat, so spans of one step can be matched up
+        with TraceAnnotation("engine.step", step=idx) as span:
+            if self.failure_injector is not None:
+                self.failure_injector.maybe_fail(idx)
+            with TraceAnnotation("engine.admit", step=idx) as admit:
+                admitted = self._admit()
+                if admitted:
+                    admit.set_metadata(
+                        uids=" ".join(str(r.uid) for _, r in admitted))
+            self._maybe_prefill()
+            active = [
+                i for i, r in enumerate(self.slots)
+                if r is not None and self._ready[i]
+            ]
+            span.set_metadata(rows=len(active))
+            if not active:
+                return len(self._pending)
+            if self.paging is not None:
+                with TraceAnnotation("engine.pages", step=idx):
+                    self._ensure_decode_pages(active)
+            with TraceAnnotation("engine.dispatch", step=idx):
+                mask = np.zeros(self.num_slots, bool)
+                mask[active] = True
+                logits, self.caches = self._step(
+                    self.params, jnp.asarray(self._next_token), self.caches,
+                    jnp.asarray(mask),
+                )
+            self.stats["decode_steps"] += 1
+            self._since_prefill += 1
+            with TraceAnnotation("engine.fetch", step=idx):
+                logits = np.asarray(logits.astype(jnp.float32))
+            with TraceAnnotation("engine.sample", step=idx):
+                self._sample_all(logits, active)
+            if self.paging is not None:
+                self.stats["pages_in_use"] = self._alloc.in_use
+                self.stats["pages_peak"] = self._alloc.peak_in_use
+            if self.census_watch is not None:
+                self._check_census()
+            return len(active) + len(self._pending)
+
+    def _sample_all(self, logits: np.ndarray, active: list[int]) -> None:
+        """Sample each active slot's next token; retire finished requests."""
         for slot in active:
             req = self.slots[slot]
             nxt = self._sample(logits, slot)
@@ -651,12 +679,6 @@ class ServingEngine:
                 req.t_done = time.perf_counter()
                 self._done_uids.add(req.uid)
                 self._free_slot(slot)
-        if self.paging is not None:
-            self.stats["pages_in_use"] = self._alloc.in_use
-            self.stats["pages_peak"] = self._alloc.peak_in_use
-        if self.census_watch is not None:
-            self._check_census()
-        return len(active) + len(self._pending)
 
     def drain(self, requests: list[Request], max_steps: int = 100_000) -> None:
         for r in requests:

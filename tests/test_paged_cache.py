@@ -133,7 +133,8 @@ def test_int8_pages_close_and_smaller(arch):
                             page_size=8, cache_dtype=dtype)
         for r in _requests(cfg.vocab_size, lens):
             eng.submit(r)
-        eng._admit()  # prefill into the pools, no decode yet
+        eng._admit()
+        eng._maybe_prefill()  # prefill into the pools, no decode yet
         mask = np.array([True, True])
         lg, eng.caches = eng._step(
             eng.params, jnp.asarray(eng._next_token), eng.caches,

@@ -106,7 +106,8 @@ def test_prefill_cache_state_matches_stepwise():
                       (b, _requests(cfg.vocab_size, lens))):
         for r in reqs:
             eng.submit(r)
-        eng._admit()  # prefill only — no decode yet
+        eng._admit()
+        eng._maybe_prefill()  # prefill only — no decode yet
     for la, lb in zip(jax.tree_util.tree_leaves(a.caches),
                       jax.tree_util.tree_leaves(b.caches)):
         np.testing.assert_allclose(

@@ -93,41 +93,78 @@ def test_quant_matmul_compiles(one_chip):
     assert _kernels(c) == 1
 
 
-def test_qwen2_decode_step_compiles_and_fits(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def qwen2_step(one_chip):
     """One jitted decode step of qwen2-1.5b at published widths (28
     layers, int8 weights, 8 slots x 512 tokens of int8 paged KV) through
-    the engine's own step function: every projection of the scanned layer
-    is a compiled kernel, and the program fits one chip's 16 GB."""
+    the engine's own step function, compiled once for the tests below."""
     from repro.configs import get_config
     from repro.core.dispatch import IntegerLinConfig
     from repro.core.qtensor import quantize_tree
     from repro.models.model import build_model
     from repro.serving import ServingEngine
 
-    # steer the platform-keyed choices (default backend, interpret mode,
-    # block table) to what they are on a TPU
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    model = build_model(get_config("qwen2-1.5b"))
-    params = jax.eval_shape(lambda key: quantize_tree(model.init(key), bits=8),
-                            jax.random.PRNGKey(0))
-    engine = ServingEngine(
-        model, params, num_slots=8, max_len=512, page_size=16,
-        cache_dtype="int8",
-        int_lin=IntegerLinConfig(policy="sorted_tiled_seq", acc_bits=24,
-                                 k_tile=64),
-    )
+    with pytest.MonkeyPatch.context() as mp:
+        # steer the platform-keyed choices (default backend, interpret
+        # mode, block table) to what they are on a TPU
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        model = build_model(get_config("qwen2-1.5b"))
+        params = jax.eval_shape(
+            lambda key: quantize_tree(model.init(key), bits=8),
+            jax.random.PRNGKey(0))
+        engine = ServingEngine(
+            model, params, num_slots=8, max_len=512, page_size=16,
+            cache_dtype="int8",
+            int_lin=IntegerLinConfig(policy="sorted_tiled_seq", acc_bits=24,
+                                     k_tile=64),
+        )
 
-    def on_chip(a):
-        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+        def on_chip(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
 
-    c = engine._step.lower(
-        jax.tree_util.tree_map(on_chip, params),
-        on_chip(jax.ShapeDtypeStruct((8, 1), jnp.int32)),
-        jax.tree_util.tree_map(on_chip, engine.caches),
-        on_chip(jax.ShapeDtypeStruct((8,), jnp.bool_)),
-    ).compile()
+        return engine._step.lower(
+            jax.tree_util.tree_map(on_chip, params),
+            on_chip(jax.ShapeDtypeStruct((8, 1), jnp.int32)),
+            jax.tree_util.tree_map(on_chip, engine.caches),
+            on_chip(jax.ShapeDtypeStruct((8,), jnp.bool_)),
+        ).compile()
+
+
+def test_qwen2_decode_step_compiles_and_fits(qwen2_step):
+    """Every projection of the scanned layer is a compiled kernel, and the
+    program fits one chip's 16 GB."""
+    c = qwen2_step
     assert _kernels(c) == 7  # q, k, v, o, gate, up, down
     mem = c.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 16e9, mem
+
+
+def test_qwen2_decode_step_names_its_work(qwen2_step):
+    """What a profile of the step is read by: the module is still
+    ``jit_step``, each kernel keeps the name the roofline reader matches
+    and sits under its policy's scope, and every instruction the step's
+    code traced carries one of the model's scopes (instructions the
+    compiler adds, such as async copies and buffer allocations, carry no
+    op_name at all)."""
+    from chipbench.cell import reader
+    from chipbench.spans import hlo_op_names, innermost_scope
+
+    kernel = reader("metrics", "pqs_dot_roofline").KERNEL
+    text = qwen2_step.as_text()
+    assert text.startswith("HloModule jit_step,")
+    kernels = [ln.strip() for ln in text.splitlines()
+               if "tpu_custom_call" in ln]
+    assert len(kernels) == 7
+    names = hlo_op_names(text)
+    for k in kernels:
+        assert kernel.match(k), k
+        assert innermost_scope(names[k[1:].split(" ")[0]]) == \
+            "pqs_dot.sorted_tiled_seq"
+    traced = {op: on for op, on in names.items()
+              if on.startswith("jit(step)/")}
+    assert len(traced) > 100
+    unscoped = {op: on for op, on in traced.items()
+                if not innermost_scope(on)}
+    assert not unscoped
