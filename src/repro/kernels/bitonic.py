@@ -14,6 +14,13 @@ the two halves, and the direction of each k-block comes from splitting
 the leading axis once more — no gathers, no ``rev``, no lane-axis
 indexing, nothing Mosaic refuses. The same code is the jnp oracle's
 network (``bitonic_sort`` sorts any axis by moving it to the front).
+
+A pairwise round (``pairwise_round_bitonic``) runs the network ONCE: the
+positives descending are the ascending sort read backwards, and the
+negatives ascending are the same sort read forwards, so one sorted tile
+and its reversal give both sides of every pair. Masking each sign with a
+sentinel and sorting twice, as the jnp oracle does, gives the same values
+with twice the comparators.
 """
 
 from __future__ import annotations
@@ -71,23 +78,40 @@ def bitonic_sort(x: jax.Array, ascending: bool = True,
     return jnp.moveaxis(x, 0, axis) if axis else x
 
 
-_NEG_INF = jnp.iinfo(jnp.int32).min
-_POS_INF = jnp.iinfo(jnp.int32).max
+def _reverse(x: jax.Array) -> jax.Array:
+    """Axis 0 reversed (length a power of two): swapping the two halves of
+    every block, at every block size, maps index i to i ^ (n-1) = n-1-i.
+    Static reshapes and picks only, like ``_exchange``."""
+    n, rest = x.shape[0], x.shape[1:]
+    j = n // 2
+    while j >= 1:
+        y = x.reshape(n // (2 * j), 2, j, *rest)
+        x = jnp.stack([y[:, 1], y[:, 0]], axis=1).reshape(x.shape)
+        j //= 2
+    return x
 
 
 def pairwise_round_bitonic(prods: jax.Array, axis: int = -1) -> jax.Array:
     """One split/sort/pairwise-add round (paper Alg. 1 body) built on the
     sorting network — semantically identical to
     ``core.sorted_accum.pairwise_round`` (tested bit-exact) but expressed
-    entirely in reshape/min/max/where, so it runs inside Pallas kernels.
+    entirely in reshape/stack/min/max, so it runs inside Pallas kernels.
+
+    One ascending sort serves both signs. With ``s`` sorted ascending
+    (length n), the positives in descending order are ``s[n-1-i]`` while
+    that is > 0, and the negatives in ascending order are ``s[i]`` while
+    that is < 0, so
+
+        out[i] = max(s[n-1-i], 0) + min(s[i], 0)
+
+    is the oracle's ``pos_sorted[i] + neg_sorted[i]`` element for element:
+    the zeros, the unpaired leftovers and the pair positions all land
+    where the two-sort sentinel form puts them. The reversal is
+    ``_reverse`` (Mosaic has no ``rev``).
     """
-    pos = jnp.where(prods > 0, prods, _NEG_INF)
-    pos = bitonic_sort(pos, ascending=False, axis=axis)  # positives first
-    pos = jnp.where(pos == _NEG_INF, 0, pos)
-    neg = jnp.where(prods < 0, prods, _POS_INF)
-    neg = bitonic_sort(neg, ascending=True, axis=axis)  # most-negative first
-    neg = jnp.where(neg == _POS_INF, 0, neg)
-    return pos + neg
+    s = bitonic_sort(jnp.moveaxis(prods, axis, 0), ascending=True, axis=0)
+    out = jnp.maximum(_reverse(s), 0) + jnp.minimum(s, 0)
+    return jnp.moveaxis(out, 0, axis)
 
 
 def sorted_order_bitonic(prods: jax.Array, rounds: int = 1,

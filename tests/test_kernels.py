@@ -5,12 +5,14 @@ import os
 import subprocess
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_shim import given, settings
 from _hypothesis_shim import strategies as st
 
+from repro.core.overflow import accumulate, partial_products
 from repro.core.pruning import nm_prune_mask
 from repro.kernels import ops, ref
 from repro.kernels.bitonic import (
@@ -18,6 +20,7 @@ from repro.kernels.bitonic import (
     pairwise_round_bitonic,
     sorted_order_bitonic,
 )
+from repro.kernels.sorted_matmul import seq_policy_matmul
 from repro.core.sorted_accum import pairwise_round, sorted_order
 
 
@@ -56,6 +59,87 @@ def test_property_pairwise_bitonic_equals_core(seed):
     np.testing.assert_array_equal(
         np.asarray(sorted_order(p, 2)), np.asarray(sorted_order_bitonic(p, 2))
     )
+
+
+def _tile(kind: str, n: int) -> np.ndarray:
+    """(4, n) partial products; the int8 extremes are 16384 =
+    (-128)^2 and -16256 = -128 * 127."""
+    r = np.random.default_rng(n)
+    if kind == "all_positive":
+        return r.integers(1, 16385, (4, n))
+    if kind == "all_negative":
+        return r.integers(-16256, 0, (4, n))
+    if kind == "all_zero":
+        return np.zeros((4, n), np.int64)
+    if kind == "one_nonzero":
+        t = np.zeros((4, n), np.int64)
+        t[np.arange(4), r.integers(0, n, 4)] = [16384, -16256, 7, -1]
+        return t
+    if kind == "ties":
+        return r.choice([-3, -1, 0, 1, 3], (4, n))
+    return r.choice([16384, -16256], (4, n))  # int8_extremes
+
+
+@pytest.mark.parametrize("axis", [0, -1])
+@pytest.mark.parametrize("n", [2, 64, 256])
+@pytest.mark.parametrize("kind", ["all_positive", "all_negative", "all_zero",
+                                  "one_nonzero", "ties", "int8_extremes"])
+def test_pairwise_bitonic_edge_tiles_match_core(kind, n, axis):
+    """The one-sort round and its rounds 1-3 equal the two-sort oracle bit
+    for bit, in the kernel's layout (sort axis leading) and the last-axis
+    one, on tiles whose sign counts sit at the extremes."""
+    p = jnp.asarray(_tile(kind, n), jnp.int32)
+    to = (lambda a: jnp.moveaxis(a, -1, 0)) if axis == 0 else (lambda a: a)
+    back = (lambda a: jnp.moveaxis(a, 0, -1)) if axis == 0 else (lambda a: a)
+    np.testing.assert_array_equal(
+        np.asarray(back(pairwise_round_bitonic(to(p), axis=axis))),
+        np.asarray(pairwise_round(p)))
+    for rounds in (1, 2, 3):
+        np.testing.assert_array_equal(
+            np.asarray(back(sorted_order_bitonic(to(p), rounds, axis=axis))),
+            np.asarray(sorted_order(p, rounds)))
+
+
+def test_seq_kernel_saturating_matches_oracle():
+    """sorted_tiled_seq in interpret mode at a 16-bit accumulator, on
+    operands whose dots leave its range (so saturation fires), equals
+    the jnp oracle."""
+    r = np.random.default_rng(16)
+    x = r.integers(90, 128, (8, 256))
+    w = r.integers(-128, 128, (128, 256))
+    w[:64, :128] = np.abs(w[:64, :128])  # sign-aligned first tiles
+    x, w = jnp.asarray(x, jnp.int8), jnp.asarray(w, jnp.int8)
+    got = np.asarray(seq_policy_matmul(
+        x, w, policy="sorted_tiled_seq", acc_bits=16, bk=128, k_tile=64,
+        interpret=True))
+    want = np.asarray(accumulate(partial_products(w, x), 16,
+                                 "sorted_tiled_seq", k_tile=64, rounds=1))
+    wide = np.asarray(x, np.int64) @ np.asarray(w, np.int64).T
+    assert (got != wide).any()
+    assert (got == 2**15 - 1).any() and (got == -(2**15)).any()
+    np.testing.assert_array_equal(got, want)
+
+
+def _elements(jaxpr, prim: str) -> int:
+    """Output elements of every ``prim`` equation, nested jaxprs included."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == prim:
+            total += sum(int(np.prod(v.aval.shape)) for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            total += _elements(sub, prim)
+    return total
+
+
+def test_pairwise_bitonic_sorts_once():
+    """One 64-wide bitonic network (21 stages of 32 compare-exchanges) plus
+    the pairing (max of the reversed tile with 0, min of the tile with 0)
+    over a (64, 8, 128) tile: a second sort would double the network."""
+    tile = jax.ShapeDtypeStruct((64, 8, 128), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda t: pairwise_round_bitonic(t, axis=0))(tile)
+    per_column = 21 * 32 + 64
+    assert _elements(jaxpr.jaxpr, "min") == per_column * 8 * 128
+    assert _elements(jaxpr.jaxpr, "max") == per_column * 8 * 128
 
 
 def test_next_pow2():
