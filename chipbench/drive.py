@@ -38,7 +38,7 @@ class Driver:
         self.loop = loop
         self.make_request = make_request
         self.records: list[Record] = []
-        self.steps = 0
+        self.step_ms: list[float] = []  # host time of each step() call
         self.t0 = self.t_end = 0.0
 
     # -- engine calls -------------------------------------------------------
@@ -59,7 +59,7 @@ class Driver:
         with TraceAnnotation("step"):
             self.engine.step()
         now = time.perf_counter()
-        self.steps += 1
+        self.step_ms.append((now - t) * 1e3)
         still = {id(r) for r in self.engine.queue}
         for rec in live:
             if rec.admitted is None and id(rec.req) in queued - still:
@@ -112,7 +112,7 @@ class Driver:
         when the engine has nothing to do the driver sleeps to the next
         arrival."""
         live = list(self.records)
-        steps_before = self.steps
+        steps_before = len(self.step_ms)
         self.t0 = time.perf_counter()
         end = self.t0 + seconds
         pending = sorted(self.specs, key=lambda s: s.due) \
@@ -138,7 +138,7 @@ class Driver:
                     with TraceAnnotation("sleep"):
                         time.sleep(max(0.0, end - now))
         self.t_end = time.perf_counter()
-        self.window_steps = self.steps - steps_before
+        self.window_step_ms = self.step_ms[steps_before:]
         self.queued_at_close = len(self.engine.queue)
         self.unfinished_at_close = sum(not r.req.done for r in self.records)
 
@@ -171,14 +171,16 @@ class Driver:
                 if r.times and r.due >= self.t0]
 
     def itl_ms(self) -> list[float]:
-        """Gaps between consecutive tokens of a request, the later one
-        emitted in the window."""
-        out = []
-        for r in self.records:
-            for a, b in zip(r.times, r.times[1:]):
-                if self.in_window(b):
-                    out.append((b - a) * 1e3)
-        return out
+        """Gaps between consecutive tokens of a request, both emitted in
+        the window.
+
+        A closed loop's last set-up token comes before the window opens,
+        so the gap from it to the first token of the window spans the end
+        of set-up, not a decode step: it is left out. An open loop sends
+        every request inside the window, so it loses none of its gaps."""
+        return [(b - a) * 1e3 for r in self.records
+                for a, b in zip(r.times, r.times[1:])
+                if self.in_window(a) and self.in_window(b)]
 
     def queue_wait_ms(self) -> list[float]:
         return [(r.admitted - r.due) * 1e3 for r in self.records

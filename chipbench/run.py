@@ -226,9 +226,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         out["breakdown"] = {"device_ops": trace_lib.top_ops(tr),
                             "idle_gaps": trace_lib.idle_gaps(tr)}
     lat = driver.lateness_ms()
+    step_ms = driver.window_step_ms
     out["run"] = {"window_s": driver.window_s(),
-                  "window_steps": driver.window_steps,
+                  "window_steps": len(step_ms),
                   "tokens_in_window": driver.tokens_in_window(),
+                  # what set itl_p95_ms: in a closed loop every row
+                  # advances together, so each gap spans one step
+                  "itl_gaps": len(driver.itl_ms()),
+                  "step_ms_max": max(step_ms) if step_ms else None,
+                  "step_ms_median": (float(np.median(step_ms)) if step_ms
+                                     else None),
                   "compiles_in_window": counter.count,
                   "queued_at_close": driver.queued_at_close,
                   "unfinished_at_close": driver.unfinished_at_close,
@@ -245,7 +252,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 def report(out: dict) -> None:
     r = out["run"]
     print(f"[run] window {r['window_s']:.3f}s, {r['window_steps']} steps, "
-          f"{r['tokens_in_window']} tokens; compiles in window "
+          f"{r['tokens_in_window']} tokens, {r['itl_gaps']} token gaps; "
+          f"step ms max {r['step_ms_max']!r}, median "
+          f"{r['step_ms_median']!r}; compiles in window "
           f"{r['compiles_in_window']}; generator late by at most "
           f"{r['generator_late_ms_max']:.1f} ms", file=sys.stderr)
     for name, c in out["checks"].items():
