@@ -11,6 +11,7 @@ is read back with the benchmark's loaders, as a traced chip run would be.
 from __future__ import annotations
 
 import dataclasses
+import gc
 
 import jax
 import numpy as np
@@ -40,6 +41,12 @@ def _requests(vocab: int, uids, lens, max_new: int) -> list[Request]:
 def traced(tmp_path_factory):
     """Two requests decode; two more arrive inside the traced window and
     are admitted at its first step."""
+    # the profiler writes every executable the process still holds into
+    # the trace: drop those of earlier tests in this worker (other
+    # engines' ``jit_step`` among them) so that the trace's one
+    # ``jit_step`` is this engine's
+    jax.clear_caches()
+    gc.collect()
     cfg = dataclasses.replace(get_config("qwen2-1.5b", smoke=True),
                               num_layers=1)
     model = build_model(cfg)
